@@ -213,4 +213,7 @@ def main(argv=()) -> None:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(sys.argv[1:])

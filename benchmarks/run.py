@@ -30,8 +30,10 @@ MODULES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Run every selected module; returns 1 when any of them raised."""
     filters = sys.argv[1:]
+    failed = []
     print("name,us_per_call,derived")
     for mod_name in MODULES:
         if filters and not any(f in mod_name for f in filters):
@@ -43,8 +45,15 @@ def main() -> None:
             print(f"# {mod_name} done in {time.perf_counter()-t0:.1f}s",
                   file=sys.stderr)
         except Exception:
+            failed.append(mod_name)
+            traceback.print_exc()
             print(f"{mod_name}/ERROR,0,{traceback.format_exc(limit=1)!r}")
+    if failed:
+        print(f"# failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    sys.exit(main())

@@ -26,6 +26,6 @@ python -m benchmarks.engine_prefill_bench --smoke
 
 echo "== telemetry smoke: traced engine session -> Chrome trace =="
 TRACE_OUT="${TRACE_OUT:-/tmp/hetis_ci_trace.json}"
-python -m repro.launch.serve --requests 4 --max-new-tokens 6 \
+python -m repro.launch.serve --smoke --requests 4 --max-new-tokens 6 \
     --trace-out "$TRACE_OUT" --trace-modules
 python -m repro.telemetry.export "$TRACE_OUT"

@@ -11,6 +11,6 @@ and dense prefill attention.  On TPU these become:
                     HBM->VMEM index_map — the TPU-native form of Hetis'
                     per-(request, head) cache fetch.
 
-Each kernel ships ``ops.py`` (jit'd wrapper; interpret=True off-TPU) and
-``ref.py`` (pure-jnp oracle for the allclose sweeps).
+Each kernel ships ``ops.py`` (jit'd wrapper; interpret=True on the CPU
+backend only) and ``ref.py`` (pure-jnp oracle for the allclose sweeps).
 """

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the prefill flash-attention kernel.
 
-Handles padding to block multiples, layout (B,S,H,dh) <-> (B,H,S,dh), and
-falls back to interpret mode off-TPU (the brief's validation path).
+Handles padding to block multiples and layout (B,S,H,dh) <-> (B,H,S,dh);
+the kernel runs in interpret mode on the CPU backend only.
 """
 
 from __future__ import annotations
@@ -14,8 +14,11 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas interpret mode runs only on the CPU backend; every other
+    backend compiles the kernel, so a TPU run can never fall back to the
+    interpreter without failing."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -40,7 +43,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     out = flash_attention_kernel(q, k, v, causal=causal, window=window,
                                  block_q=bq, block_k=bk, kv_len=Sk,
-                                 interpret=not _on_tpu())
+                                 interpret=_interpret())
     out = out[:, :, :Sq]
     if layout == "BSHD":
         out = out.transpose(0, 2, 1, 3)

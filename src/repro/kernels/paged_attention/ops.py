@@ -11,8 +11,11 @@ from repro.kernels.paged_attention.kernel import (
     paged_attention_kernel, paged_prefill_attention_kernel)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas interpret mode runs only on the CPU backend; every other
+    backend compiles the kernel, so a TPU run can never fall back to the
+    interpreter without failing."""
+    return jax.default_backend() == "cpu"
 
 
 @jax.jit
@@ -32,7 +35,7 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     return paged_attention_kernel(q, kpool, vpool,
                                   block_tables.astype(jnp.int32),
                                   lengths.astype(jnp.int32),
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 @jax.jit
@@ -56,5 +59,5 @@ def paged_prefill_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     out = paged_prefill_attention_kernel(
         q.reshape(B, Hkv, C * r, dh), kpool, vpool,
         block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        starts.astype(jnp.int32), r=r, interpret=not _on_tpu())
+        starts.astype(jnp.int32), r=r, interpret=_interpret())
     return out.reshape(B, Hkv, C, r, dh)

@@ -1,30 +1,73 @@
-"""Serving launcher: run the Hetis engine end-to-end on a (reduced) model.
+"""Serving launcher: run the Hetis engine end-to-end.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
       --requests 8 --rate 2.0
 
-Full-size archs on real pods would load checkpoints and use the production
-mesh; on CPU the ``--smoke`` reduced config exercises the identical control
-plane (Dispatcher LP, paged head cache, re-dispatching, eviction).
+Without ``--smoke`` the model is the published config of ``--arch`` in
+bf16, with random weights drawn from ``--seed`` (no checkpoint ships with
+the repo); the defaults ``--max-seq 512 --max-batch 16`` size the KV pools
+so that weights, pools and the largest fused step fit one 16 GB v5e chip.
+``--smoke`` serves the reduced float32 sibling of the same family, the
+size CPU runs use.  An arch the paged, fused path cannot serve is refused.
 """
 
 from __future__ import annotations
 
 import argparse
+from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs import ALIASES, get_config, smoke_config
 from repro.core.cluster import ClusterSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
+from repro.models.config import ModelConfig
 from repro.serving import EngineConfig, InferenceEngine, Request
 
 
-def main() -> None:
+def load_model(arch: str, smoke: bool, seed: int) -> Tuple[ModelConfig,
+                                                             dict]:
+    """The served config (published, or its reduced sibling) and random
+    weights from ``seed``; raises ValueError for an arch the served path
+    cannot run."""
+    cfg = smoke_config(arch) if smoke else get_config(arch)
+    why = T.fused_step_blocker(cfg)
+    if why:
+        raise ValueError(f"{arch} cannot be served: {why}")
+    return cfg, T.init_params(cfg, jax.random.PRNGKey(seed))
+
+
+# The served deployment: an A100-class primary plus three pool devices
+# (two 3090s, one P100) that hold offloaded head groups.
+CLUSTER = (("A100", 1), ("3090", 2), ("P100", 1))
+PRIMARY_IDS, POOL_IDS = (0,), (1, 2, 3)
+# Largest defaults whose weights, pools and largest fused-step temporaries
+# fit one 16 GB v5e chip at qwen1.5-0.5b width (``tests/test_tpu_compile.py``
+# compiles that step for the chip and checks the sum).
+MAX_SEQ, MAX_BATCH = 512, 16
+
+
+def build_engine(cfg: ModelConfig, params, *, max_seq: int = MAX_SEQ,
+                 max_batch: int = MAX_BATCH, telemetry: bool = False,
+                 trace_modules: bool = False) -> InferenceEngine:
+    """The served engine on the deployment above."""
+    return InferenceEngine(cfg, params, ClusterSpec.build(CLUSTER),
+                           primary_ids=PRIMARY_IDS, pool_ids=POOL_IDS,
+                           engine_cfg=EngineConfig(
+                               max_batch=max_batch, max_seq=max_seq,
+                               telemetry=telemetry,
+                               trace_modules=trace_modules))
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", default="qwen1.5-0.5b", choices=sorted(ALIASES))
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced float32 sibling (CPU size)")
+    ap.add_argument("--max-seq", type=int, default=MAX_SEQ)
+    ap.add_argument("--max-batch", type=int, default=MAX_BATCH)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--rate", type=float, default=2.0)
     ap.add_argument("--max-new-tokens", type=int, default=16)
@@ -36,24 +79,20 @@ def main() -> None:
                     help="also run the eager per-module probe (device-"
                          "sync'd Attention/MLP spans feeding dispatcher/"
                          "hauler/costmodel calibration)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = smoke_config(args.arch)
-    if cfg.attn_type != "gqa" or cfg.is_encoder_only:
-        # engine's paged path is GQA-only (DESIGN §3); fall back to a
-        # GQA-family smoke config for the demo
-        cfg = smoke_config("qwen3-14b")
-        print(f"# note: {args.arch} engine demo uses the qwen3 smoke family")
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
+    enable_compile_cache()
+    try:
+        cfg, params = load_model(args.arch, args.smoke, args.seed)
+    except ValueError as e:
+        ap.error(str(e))
+    print(f"# {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+          f"{cfg.dtype}, backend {jax.default_backend()}")
 
-    cluster = ClusterSpec.build([("A100", 1), ("3090", 2), ("P100", 1)])
-    telemetry = bool(args.trace_out) or args.trace_modules
-    eng = InferenceEngine(cfg, params, cluster, primary_ids=[0],
-                          pool_ids=[1, 2, 3],
-                          engine_cfg=EngineConfig(
-                              max_batch=16, max_seq=128,
-                              telemetry=telemetry,
-                              trace_modules=args.trace_modules))
+    eng = build_engine(cfg, params, max_seq=args.max_seq,
+                       max_batch=args.max_batch,
+                       telemetry=bool(args.trace_out) or args.trace_modules,
+                       trace_modules=args.trace_modules)
 
     rng = np.random.default_rng(args.seed)
     t = 0.0
@@ -65,12 +104,12 @@ def main() -> None:
                            max_new_tokens=args.max_new_tokens, arrival=t))
     eng.run_until_drained()
     print(f"served {len(eng.finished)} requests, "
-          f"sim clock {eng.clock*1e3:.2f} ms, metrics {eng.metrics}")
+          f"modeled clock {eng.clock*1e3:.2f} ms, metrics {eng.metrics}")
     for r in eng.finished[:4]:
-        print(f"  rid={r.rid} ttft={r.ttft*1e3:.2f}ms "
+        print(f"  rid={r.rid} modeled ttft={r.ttft*1e3:.2f}ms "
               f"tokens={r.output[:8]}...")
     snap = eng.snapshot()
-    print(f"snapshot: ttft_p95={snap['ttft_s/p95']*1e3:.3f}ms "
+    print(f"snapshot: modeled ttft_p95={snap['ttft_s/p95']*1e3:.3f}ms "
           f"kv_occupancy={snap['kv/occupancy']:.3f} "
           f"recompiles={snap['jit/recompiles']:.0f}")
     if args.trace_out:

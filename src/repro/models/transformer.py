@@ -417,13 +417,35 @@ def _apply_decode_carry(cfg: ModelConfig, kind: str, p: Params,
     return x, caches
 
 
+def _paged_decode_blocker(cfg: ModelConfig) -> str:
+    """Why decode cannot run against the head-granular paged pool ('' when
+    it can).  Pure-GQA full-attention stacks only; MLA (latent cache),
+    SSM/hybrid (recurrent state), xLSTM and sliding-window configs use the
+    dense reference path."""
+    if cfg.attn_type != "gqa":
+        return (f"attention type {cfg.attn_type!r} has no head-granular "
+                f"K/V pool (GQA only)")
+    if cfg.xlstm_pattern or cfg.ssm_state:
+        return "recurrent layer state has no place in the paged K/V pool"
+    if cfg.sliding_window:
+        return "the paged kernels have no sliding-window bound"
+    if cfg.is_encoder_only:
+        return "an encoder-only model has no decode step"
+    return ""
+
+
+def fused_step_blocker(cfg: ModelConfig) -> str:
+    """Why the served path (paged pools + fused prefill/decode step)
+    cannot run ``cfg`` — '' when it can."""
+    why = _paged_decode_blocker(cfg)
+    if not why and cfg.frontend in ("audio_stub", "vision_stub"):
+        why = f"the {cfg.frontend} frontend has no token-chunk prefill"
+    return why
+
+
 def supports_paged_decode(cfg: ModelConfig) -> bool:
-    """Can decode run against the head-granular paged pool?  Pure-GQA
-    full-attention stacks only; MLA (latent cache), SSM/hybrid (recurrent
-    state), xLSTM and sliding-window configs use the dense reference path."""
-    return (cfg.attn_type == "gqa" and not cfg.xlstm_pattern
-            and not cfg.ssm_state and not cfg.sliding_window
-            and not cfg.is_encoder_only)
+    """Can decode run against the head-granular paged pool?"""
+    return not _paged_decode_blocker(cfg)
 
 
 def paged_decode_step(cfg: ModelConfig, params: Params,
@@ -533,8 +555,7 @@ def paged_decode_step_traced(cfg: ModelConfig, params: Params,
 def supports_paged_prefill(cfg: ModelConfig) -> bool:
     """Chunked paged prefill shares the paged-decode support envelope:
     pure-GQA full-attention stacks with a token embedding frontend."""
-    return supports_paged_decode(cfg) and cfg.frontend not in (
-        "audio_stub", "vision_stub")
+    return not fused_step_blocker(cfg)
 
 
 def _paged_chunk_forward(cfg: ModelConfig, params: Params,
@@ -668,7 +689,7 @@ def paged_prefill_chunk(cfg: ModelConfig, params: Params,
 def supports_fused_step(cfg: ModelConfig) -> bool:
     """The fused prefill+decode step needs BOTH paged paths: decode rows
     are degenerate chunks through the chunked-prefill kernel family."""
-    return supports_paged_decode(cfg) and supports_paged_prefill(cfg)
+    return not fused_step_blocker(cfg)
 
 
 def paged_fused_step(cfg: ModelConfig, params: Params,

@@ -197,6 +197,39 @@ class EngineConfig:
     trace_capacity: int = 65536     # tracer ring-buffer size (spans)
 
 
+def _cache_bytes(ecfg: EngineConfig, dev: Device) -> float:
+    """KV-cache bytes the dispatcher may place on one device."""
+    cap_gb = (ecfg.cache_gb_per_device or {}).get(dev.device_id,
+                                                  dev.cls.mem_gb * 0.3)
+    return cap_gb * 1e9
+
+
+def pool_shard_slots(cfg: ModelConfig, cluster: ClusterSpec,
+                     device_ids: Sequence[int], ecfg: EngineConfig
+                     ) -> Tuple[Dict[int, int], int]:
+    """(pool slots per device shard, anchor staging slots) of an engine —
+    the pool arrays' sizes, known before any is allocated.
+
+    A shard holds what the device's cache bytes allow, but never more than
+    backing ``max_batch`` sequences at ``max_seq`` with every head group on
+    that one device: capacity beyond that is dispatcher bookkeeping, not
+    pool memory.  The anchor's staging region must hold every remote page
+    one step can reference — max_batch rows x n_kv_heads chains x
+    pages_per_seq pages, the same cap (single-shard engines need none)."""
+    devs = {d.device_id: d for d in cluster.devices}
+    # bytes per pool slot from the pool's actual dtype
+    slot_bytes = (2 * cfg.n_layers * ecfg.page_size * cfg.head_dim
+                  * PagedHeadCache.pool_dtype(cfg).itemsize)
+    pages_per_seq = -(-ecfg.max_seq // ecfg.page_size)
+    pool_cap = ecfg.max_batch * cfg.n_kv_heads * pages_per_seq
+    slots = {}
+    for did in device_ids:
+        by_mem = max(1, int(_cache_bytes(ecfg, devs[did]) / max(1, slot_bytes)
+                            / max(1, cfg.n_kv_heads)))
+        slots[did] = min(by_mem, pool_cap)
+    return slots, (pool_cap if len(slots) > 1 else 0)
+
+
 class InferenceEngine:
     def __init__(self, cfg: ModelConfig, params, cluster: ClusterSpec,
                  primary_ids: Sequence[int], pool_ids: Sequence[int],
@@ -213,38 +246,20 @@ class InferenceEngine:
         # Dispatcher worker states from analytic profiler models
         devs = self._devs
         self.workers: List[WorkerState] = []
-        # bytes per pool slot from the pool's actual dtype (no hardcoded
-        # "* 4": bf16/fp32 configs report what the arrays really occupy)
-        pool_itemsize = PagedHeadCache.pool_dtype(cfg).itemsize
-        slot_bytes = (2 * cfg.n_layers * engine_cfg.page_size * cfg.head_dim
-                      * pool_itemsize)
-        # physical pool only needs to back max_batch concurrent sequences
-        # at max_seq, even if every head group lands on one device —
-        # capacity beyond that is dispatcher bookkeeping, not pool memory
-        # (the pools are real device allocations now, not lazy zeros).
-        pages_per_seq = -(-engine_cfg.max_seq // engine_cfg.page_size)
-        pool_cap = engine_cfg.max_batch * cfg.n_kv_heads * pages_per_seq
-        self.device_slots: Dict[int, int] = {}
-        for did in list(primary_ids) + list(pool_ids):
+        device_ids = list(primary_ids) + list(pool_ids)
+        for did in device_ids:
             d = devs[did]
             attn_model = analytic_attention_model(d.cls, self.profile)
             xfer = (None if did in primary_ids else
                     analytic_transfer_model(d.cls.inter_link_gbps))
-            cap_gb = (engine_cfg.cache_gb_per_device or {}).get(
-                did, d.cls.mem_gb * 0.3)
-            cap_bytes = cap_gb * 1e9
-            self.workers.append(WorkerState(did, attn_model, xfer,
-                                            capacity_bytes=cap_bytes))
-            by_mem = max(1, int(cap_bytes / max(1, slot_bytes)
-                                / max(1, cfg.n_kv_heads)))
-            self.device_slots[did] = min(by_mem, pool_cap)
+            self.workers.append(WorkerState(
+                did, attn_model, xfer,
+                capacity_bytes=_cache_bytes(engine_cfg, d)))
         self.primary_ids = list(primary_ids)
 
-        # Per-device pool shards, anchored on the first primary.  The
-        # anchor's staging region must hold every remote page one step can
-        # reference: <= max_batch rows x n_kv_heads chains x pages_per_seq
-        # pages == pool_cap (single-partition engines need no staging).
-        stage = pool_cap if len(self.device_slots) > 1 else 0
+        # Per-device pool shards, anchored on the first primary.
+        self.device_slots, stage = pool_shard_slots(cfg, cluster, device_ids,
+                                                    engine_cfg)
         self.kv = PagedHeadCache(cfg, self.device_slots,
                                  page_size=engine_cfg.page_size,
                                  anchor=self.primary_ids[0],
@@ -344,10 +359,10 @@ class InferenceEngine:
         self._prefill_fn = count_recompiles(jax.jit(
             lambda p, b: T.prefill(cfg, p, b, max_seq=engine_cfg.max_seq)),
             self._c_recompiles)
-        # buffer donation lets XLA update the pool-shard pytrees in place;
-        # CPU does not support donation (harmless, but noisy), so only
-        # donate off-CPU.
-        donate = (1, 2) if jax.default_backend() != "cpu" else ()
+        # buffer donation lets XLA update the pool-shard pytrees in place:
+        # the pool dicts passed in are dead after the call, and the engine
+        # holds only the dicts each call returns (``kv.install_pools``).
+        donate = (1, 2)
         # anchor / anchor-sink are static (baked into the trace); the
         # exchange lane arrays (gd/gs/gt gathers, wd/ws_/wt writebacks)
         # stage remote pool shards' pages through the anchor inside the
@@ -379,6 +394,12 @@ class InferenceEngine:
         self.use_fused = (engine_cfg.step_mode == "fused"
                           and self.use_paged and self.use_paged_prefill
                           and T.supports_fused_step(cfg))
+        why = T.fused_step_blocker(cfg)
+        if why and "paged" in (engine_cfg.decode_mode,
+                               engine_cfg.prefill_mode):
+            warnings.warn(f"{cfg.name} falls back from the requested paged "
+                          f"path to the dense reference path: {why}",
+                          RuntimeWarning, stacklevel=2)
         # autotuned per-step prefill chunk, pow2 in [1, prefill_chunk]
         self._chunk_now = _bucket(engine_cfg.prefill_chunk)
 
@@ -527,17 +548,11 @@ class InferenceEngine:
 
     def decode_compile_count(self) -> int:
         """Actual number of paged-decode compilations so far."""
-        try:
-            return int(self._paged_fn._cache_size())
-        except Exception:               # jax without _cache_size
-            return len(self._decode_shapes)
+        return int(self._paged_fn._cache_size())
 
     def prefill_compile_count(self) -> int:
         """Actual number of chunked-prefill compilations so far."""
-        try:
-            return int(self._chunk_fn._cache_size())
-        except Exception:               # jax without _cache_size
-            return len(self._prefill_shapes)
+        return int(self._chunk_fn._cache_size())
 
     def fused_bucket_count(self) -> int:
         """Upper bound on fused-step jit compilations: one per
@@ -546,10 +561,7 @@ class InferenceEngine:
 
     def fused_compile_count(self) -> int:
         """Actual number of fused-step compilations so far."""
-        try:
-            return int(self._fused_fn._cache_size())
-        except Exception:               # jax without _cache_size
-            return len(self._fused_shapes)
+        return int(self._fused_fn._cache_size())
 
     # ------------------------------------------------------------------ admit
     def submit(self, req: Request) -> None:

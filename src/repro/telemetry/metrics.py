@@ -199,18 +199,11 @@ class RecompileCountingFn:
         self.counter = counter
 
     def __call__(self, *args, **kwargs):
-        try:
-            before = self.fn._cache_size()
-        except Exception:
-            before = None
+        before = self.fn._cache_size()
         out = self.fn(*args, **kwargs)
-        if before is not None:
-            try:
-                after = self.fn._cache_size()
-            except Exception:
-                after = before
-            if after > before:
-                self.counter.inc(after - before)
+        after = self.fn._cache_size()
+        if after > before:
+            self.counter.inc(after - before)
         return out
 
     def _cache_size(self) -> int:

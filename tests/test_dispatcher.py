@@ -4,7 +4,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.dispatcher import (AttnRequest, WorkerState, apply_placement,
                                    current_attention_time, dispatch_lp,

@@ -3,7 +3,7 @@ migration, step-plan staging remap — plus hypothesis invariants."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.config import ModelConfig
 from repro.serving.kvcache import PagedHeadCache
