@@ -160,17 +160,21 @@ def gqa_decode_paged(cfg, p, x, kpool, vpool, idx, block_tables, lengths,
     from repro.kernels.paged_attention import paged_attention
     B = x.shape[0]
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _gqa_qkv(cfg, p, x, pos[:, None])
-    cdt = kpool.dtype                        # may be f8 (kv_cache_dtype)
-    kpool = kpool.at[idx, write_slot, write_off[:, None]].set(
-        k[:, 0].astype(cdt))
-    vpool = vpool.at[idx, write_slot, write_off[:, None]].set(
-        v[:, 0].astype(cdt))
-    # group-major head fold (H = Hkv * r), matching attention_core
-    qg = q[:, 0].reshape(B, Hkv, H // Hkv, dh)
-    out = paged_attention(qg, kpool[idx].astype(q.dtype),
-                          vpool[idx].astype(q.dtype), block_tables, lengths)
-    out = out.reshape(B, 1, H * dh) @ p["wo"]
+    with jax.named_scope("qkv"):
+        q, k, v = _gqa_qkv(cfg, p, x, pos[:, None])
+    with jax.named_scope("kv_write"):
+        cdt = kpool.dtype                    # may be f8 (kv_cache_dtype)
+        kpool = kpool.at[idx, write_slot, write_off[:, None]].set(
+            k[:, 0].astype(cdt))
+        vpool = vpool.at[idx, write_slot, write_off[:, None]].set(
+            v[:, 0].astype(cdt))
+    with jax.named_scope("attn"):
+        # group-major head fold (H = Hkv * r), matching attention_core
+        qg = q[:, 0].reshape(B, Hkv, H // Hkv, dh)
+        out = paged_attention(qg, kpool[idx].astype(q.dtype),
+                              vpool[idx].astype(q.dtype), block_tables,
+                              lengths)
+        out = out.reshape(B, 1, H * dh) @ p["wo"]
     return logical(out, "batch", "seq", "embed"), kpool, vpool
 
 
@@ -201,18 +205,21 @@ def gqa_prefill_paged(cfg, p, x, kpool, vpool, idx, block_tables, lengths,
     from repro.kernels.paged_attention import paged_prefill_attention
     B, C, _ = x.shape
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _gqa_qkv(cfg, p, x, positions)
-    cdt = kpool.dtype                        # may be f8 (kv_cache_dtype)
-    kpool = kpool.at[idx, write_slots, write_offs[:, None, :]].set(
-        jnp.swapaxes(k, 1, 2).astype(cdt))
-    vpool = vpool.at[idx, write_slots, write_offs[:, None, :]].set(
-        jnp.swapaxes(v, 1, 2).astype(cdt))
-    # group-major head fold (H = Hkv * r), matching attention_core
-    qg = q.reshape(B, C, Hkv, H // Hkv, dh).transpose(0, 2, 1, 3, 4)
-    out = paged_prefill_attention(qg, kpool[idx].astype(q.dtype),
-                                  vpool[idx].astype(q.dtype), block_tables,
-                                  lengths, starts)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(B, C, H * dh) @ p["wo"]
+    with jax.named_scope("qkv"):
+        q, k, v = _gqa_qkv(cfg, p, x, positions)
+    with jax.named_scope("kv_write"):
+        cdt = kpool.dtype                    # may be f8 (kv_cache_dtype)
+        kpool = kpool.at[idx, write_slots, write_offs[:, None, :]].set(
+            jnp.swapaxes(k, 1, 2).astype(cdt))
+        vpool = vpool.at[idx, write_slots, write_offs[:, None, :]].set(
+            jnp.swapaxes(v, 1, 2).astype(cdt))
+    with jax.named_scope("attn"):
+        # group-major head fold (H = Hkv * r), matching attention_core
+        qg = q.reshape(B, C, Hkv, H // Hkv, dh).transpose(0, 2, 1, 3, 4)
+        out = paged_prefill_attention(qg, kpool[idx].astype(q.dtype),
+                                      vpool[idx].astype(q.dtype),
+                                      block_tables, lengths, starts)
+        out = out.transpose(0, 2, 1, 3, 4).reshape(B, C, H * dh) @ p["wo"]
     return logical(out, "batch", "seq", "embed"), kpool, vpool
 
 

@@ -467,11 +467,13 @@ def paged_decode_step(cfg: ModelConfig, params: Params,
     other operands documented in ``attn.gqa_decode_paged``.
     """
     assert supports_paged_decode(cfg), "config not supported by paged decode"
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = logical(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x = logical(x, "batch", "seq", "embed")
     layer0 = 0
     for gi, (kind, n, _win) in enumerate(layer_groups(cfg)):
 
+        @jax.named_scope("layer")
         def body(carry, layer_in, _kind=kind):
             xx, kp, vp = carry
             p_l, idx = layer_in
@@ -480,23 +482,32 @@ def paged_decode_step(cfg: ModelConfig, params: Params,
                 cfg, p_l["attn"], xn, kp, vp, idx, block_tables, lengths,
                 write_slot, write_off, pos)
             xx = xx + a_out
-            if "mlp" in p_l:
-                xn = rmsnorm(xx, p_l["mlp_norm"], cfg.norm_eps)
-                if _kind.endswith("moe"):
-                    m_out, _ = mlp_mod.moe_apply(cfg, p_l["mlp"], xn)
-                else:
-                    m_out = mlp_mod.mlp_apply(cfg, p_l["mlp"], xn)
-                xx = xx + m_out
-            return (xx, kp, vp), None
+            return (_mlp_residual(cfg, _kind, p_l, xx), kp, vp), None
 
         (x, kpool, vpool), _ = jax.lax.scan(
             body, (x, kpool, vpool),
             (params["groups"][gi], layer0 + jnp.arange(n)))
         layer0 += n
-    h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (h[:, 0] @ _lm_head(cfg, params)).astype(jnp.float32)
-    logits = logical(logits, "batch", "vocab")
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = (h[:, 0] @ _lm_head(cfg, params)).astype(jnp.float32)
+        logits = logical(logits, "batch", "vocab")
     return logits, kpool, vpool
+
+
+def _mlp_residual(cfg: ModelConfig, kind: str, p_l: Params, x: jax.Array
+                  ) -> jax.Array:
+    """``x`` plus the layer's MLP (or MoE) of its normed input — the
+    ``mlp`` scope of the paged steps' layer body."""
+    if "mlp" not in p_l:
+        return x
+    with jax.named_scope("mlp"):
+        xn = rmsnorm(x, p_l["mlp_norm"], cfg.norm_eps)
+        if kind.endswith("moe"):
+            m_out, _ = mlp_mod.moe_apply(cfg, p_l["mlp"], xn)
+        else:
+            m_out = mlp_mod.mlp_apply(cfg, p_l["mlp"], xn)
+        return x + m_out
 
 
 def paged_decode_step_traced(cfg: ModelConfig, params: Params,
@@ -569,13 +580,15 @@ def _paged_chunk_forward(cfg: ModelConfig, params: Params,
     embed a (B, C) token block, scatter its K/V into the pools, run the
     chunked-prefill Pallas kernel causally through the block tables, and
     return each row's last-valid-token logits."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = logical(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x = logical(x, "batch", "seq", "embed")
     C = tokens.shape[1]
     positions = starts[:, None] + jnp.arange(C)[None, :]
     layer0 = 0
     for gi, (kind, n, _win) in enumerate(layer_groups(cfg)):
 
+        @jax.named_scope("layer")
         def body(carry, layer_in, _kind=kind):
             xx, kp, vp = carry
             p_l, idx = layer_in
@@ -584,23 +597,17 @@ def _paged_chunk_forward(cfg: ModelConfig, params: Params,
                 cfg, p_l["attn"], xn, kp, vp, idx, block_tables, lengths,
                 starts, write_slots, write_offs, positions)
             xx = xx + a_out
-            if "mlp" in p_l:
-                xn = rmsnorm(xx, p_l["mlp_norm"], cfg.norm_eps)
-                if _kind.endswith("moe"):
-                    m_out, _ = mlp_mod.moe_apply(cfg, p_l["mlp"], xn)
-                else:
-                    m_out = mlp_mod.mlp_apply(cfg, p_l["mlp"], xn)
-                xx = xx + m_out
-            return (xx, kp, vp), None
+            return (_mlp_residual(cfg, _kind, p_l, xx), kp, vp), None
 
         (x, kpool, vpool), _ = jax.lax.scan(
             body, (x, kpool, vpool),
             (params["groups"][gi], layer0 + jnp.arange(n)))
         layer0 += n
-    h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)
-    logits = (last[:, 0] @ _lm_head(cfg, params)).astype(jnp.float32)
-    logits = logical(logits, "batch", "vocab")
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        last = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)
+        logits = (last[:, 0] @ _lm_head(cfg, params)).astype(jnp.float32)
+        logits = logical(logits, "batch", "vocab")
     return logits, kpool, vpool
 
 
@@ -741,9 +748,7 @@ def paged_fused_step_traced(cfg: ModelConfig, params: Params,
     """Instrumented twin of ``paged_fused_step`` — eager layer loop with
     per-module Attention / MLP spans.  ``span_args`` should carry the
     per-phase row/token split (``decode_rows``/``prefill_tokens``...) so
-    span consumers can attribute one call's time to both phases; the
-    engine additionally emits proportional ``fused/<phase>`` child spans
-    (see ``Tracer.add_phase_spans``)."""
+    span consumers can attribute one call's time to both phases."""
     assert supports_fused_step(cfg), "config not supported by fused step"
     return _paged_chunk_forward_traced(cfg, params, kpool, vpool,
                                        block_tables, lengths, starts,
@@ -788,14 +793,15 @@ def _pool_exchange_in(kpools, vpools, anchor: int, anchor_sink: int,
     ak, av = kpools[anchor], vpools[anchor]
     if g_dev.shape[0] == 0:
         return ak, av
-    for dev in sorted(d for d in kpools if d != anchor):
-        kp, vp = kpools[dev], vpools[dev]
-        rsink = kp.shape[1] - 1
-        m = g_dev == dev
-        src = jnp.where(m, g_src, rsink)
-        dst = jnp.where(m, g_dst, anchor_sink)
-        ak = ak.at[:, dst].set(kp[:, src])
-        av = av.at[:, dst].set(vp[:, src])
+    with jax.named_scope("exchange_in"):
+        for dev in sorted(d for d in kpools if d != anchor):
+            kp, vp = kpools[dev], vpools[dev]
+            rsink = kp.shape[1] - 1
+            m = g_dev == dev
+            src = jnp.where(m, g_src, rsink)
+            dst = jnp.where(m, g_dst, anchor_sink)
+            ak = ak.at[:, dst].set(kp[:, src])
+            av = av.at[:, dst].set(vp[:, src])
     return ak, av
 
 
@@ -811,14 +817,15 @@ def _pool_exchange_out(kpools, vpools, anchor: int, anchor_sink: int,
     if w_dev.shape[0] == 0:
         return kpools, vpools
     ak, av = kpools[anchor], vpools[anchor]
-    for dev in sorted(d for d in kpools if d != anchor):
-        kp, vp = kpools[dev], vpools[dev]
-        rsink = kp.shape[1] - 1
-        m = w_dev == dev
-        src = jnp.where(m, w_src, anchor_sink)
-        dst = jnp.where(m, w_dst, rsink)
-        kpools[dev] = kp.at[:, dst].set(ak[:, src])
-        vpools[dev] = vp.at[:, dst].set(av[:, src])
+    with jax.named_scope("exchange_out"):
+        for dev in sorted(d for d in kpools if d != anchor):
+            kp, vp = kpools[dev], vpools[dev]
+            rsink = kp.shape[1] - 1
+            m = w_dev == dev
+            src = jnp.where(m, w_src, anchor_sink)
+            dst = jnp.where(m, w_dst, rsink)
+            kpools[dev] = kp.at[:, dst].set(ak[:, src])
+            vpools[dev] = vp.at[:, dst].set(av[:, src])
     return kpools, vpools
 
 

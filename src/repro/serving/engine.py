@@ -79,15 +79,20 @@ default whenever both paged paths apply):
 
 Telemetry (``repro.telemetry``): a typed :class:`MetricsRegistry` replaces
 the old flat metrics dict — byte counters are computed from the actual
-array dtypes, TTFT/TPOT/step-latency are histograms whose percentiles are
-evaluated lazily at read time, KV-pool occupancy and per-device memory are
-callable-backed gauges, and every jitted callable is wrapped with a
-jit-recompile counter.  ``engine.metrics`` stays a backward-compatible
-mapping view over the registry; ``engine.snapshot()`` is the typed API.
-With ``EngineConfig.telemetry`` on, a :class:`Tracer` records nested
-admit/prefill_chunk/paged_decode/rebalance spans (plus modeled module
-spans on a simulated-clock track), exportable as Chrome ``trace_event``
-JSON; ``trace_modules`` additionally runs the eager per-module probe
+array dtypes, TTFT/TPOT are histograms whose percentiles are evaluated
+lazily at read time, KV-pool occupancy and per-device memory are
+callable-backed gauges, every jitted callable is wrapped with a
+jit-recompile counter, and the wall-clock counters ``queue/wait_s`` /
+``queue/served`` (submit to first scheduled chunk) are always on.  ``engine.metrics`` stays a backward-compatible mapping
+view over the registry; ``engine.snapshot()`` is the typed API.  With
+``EngineConfig.telemetry`` on, a :class:`Tracer` records the nested spans
+``step`` > ``admit`` (> ``lp``, ``alloc``) / ``plan`` / ``upload`` /
+``fused_step`` / ``readback`` / ``rebalance`` (> ``migrate``), each also a
+``jax.profiler`` annotation ``engine.<name>`` on the profiler's clock,
+exportable as Chrome ``trace_event`` JSON; the jitted steps are named
+functions (``jit_fused_step``, ...) whose bodies carry
+``jax.named_scope``s.  ``trace_modules`` additionally runs the eager
+per-module probe
 (``transformer.paged_decode_step_traced``) whose device-sync'd
 Attention/MLP span durations feed the dispatcher's measured snapshot
 (EWMA-smoothed per-device gauges consumed by ``maybe_rebalance``), the
@@ -104,7 +109,8 @@ import collections
 import dataclasses
 import time
 import warnings
-from typing import Deque, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Deque, Dict, Iterable, List, Optional, Sequence, Set,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -230,6 +236,53 @@ def pool_shard_slots(cfg: ModelConfig, cluster: ClusterSpec,
     return slots, (pool_cap if len(slots) > 1 else 0)
 
 
+def jitted_steps(cfg: ModelConfig, max_seq: int, anchor: int,
+                 anchor_sink: int) -> Tuple:
+    """The engine's jitted callables, each a named function so its module
+    reads ``jit_<name>`` in a profile and in dumped HLO: (dense decode,
+    dense prefill, paged decode, paged prefill chunk, fused step).
+
+    The paged steps donate the pool-shard pytrees (arguments 1 and 2), so
+    XLA updates them in place: the pool dicts passed in are dead after the
+    call, and the engine holds only the dicts each call returns
+    (``kv.install_pools``).  ``anchor`` / ``anchor_sink`` are static
+    (baked into the trace); the exchange lane arrays stage remote pool
+    shards' pages through the anchor inside the same jitted call (see
+    ``transformer.sharded_decode_step``)."""
+
+    # the argument names become the HLO parameters' names: gd/gs/gt gather
+    # lanes, wd/ws_/wt (wsb) writeback lanes, then the step's own operands
+    def dense_decode(p, c, t):
+        return T.decode_step(cfg, p, c, t)
+
+    def dense_prefill(p, b):
+        return T.prefill(cfg, p, b, max_seq=max_seq)
+
+    def decode_step(p, kp, vp, gd, gs, gt, wd, ws_, wt, bt, ln, ws, wo, t,
+                    pos):
+        return T.sharded_decode_step(
+            cfg, p, kp, vp, anchor, anchor_sink, gd, gs, gt, wd, ws_, wt,
+            bt, ln, ws, wo, t, pos)
+
+    def prefill_chunk(p, kp, vp, gd, gs, gt, wd, wsb, wt, bt, ln, st, ws,
+                      wo, t, li):
+        return T.sharded_prefill_chunk(
+            cfg, p, kp, vp, anchor, anchor_sink, gd, gs, gt, wd, wsb, wt,
+            bt, ln, st, ws, wo, t, li)
+
+    def fused_step(p, kp, vp, gd, gs, gt, wd, wsb, wt, bt, ln, st, ws, wo,
+                   t, li):
+        return T.sharded_fused_step(
+            cfg, p, kp, vp, anchor, anchor_sink, gd, gs, gt, wd, wsb, wt,
+            bt, ln, st, ws, wo, t, li)
+
+    donate = (1, 2)
+    return (jax.jit(dense_decode), jax.jit(dense_prefill),
+            jax.jit(decode_step, donate_argnums=donate),
+            jax.jit(prefill_chunk, donate_argnums=donate),
+            jax.jit(fused_step, donate_argnums=donate))
+
+
 class InferenceEngine:
     def __init__(self, cfg: ModelConfig, params, cluster: ClusterSpec,
                  primary_ids: Sequence[int], pool_ids: Sequence[int],
@@ -284,7 +337,8 @@ class InferenceEngine:
         # ------------------------------------------------------- telemetry
         self.registry = MetricsRegistry()
         self.tracer = Tracer(enabled=engine_cfg.telemetry,
-                             capacity=engine_cfg.trace_capacity)
+                             capacity=engine_cfg.trace_capacity,
+                             profiler_prefix="engine.")
         self._trace_modules = (engine_cfg.telemetry
                                and engine_cfg.trace_modules)
         reg = self.registry
@@ -314,7 +368,10 @@ class InferenceEngine:
         reg.gauge("prefill/chunk_now", fn=lambda: float(self._chunk_now))
         self._h_ttft = reg.histogram("ttft_s")
         self._h_tpot = reg.histogram("tpot_s")
-        self._h_step = reg.histogram("step_latency_s")
+        # wall-clock seconds from submit to the step that first schedules
+        # a chunk of the request, and the requests they sum over
+        self._c_wait = reg.counter("queue/wait_s")
+        self._c_served = reg.counter("queue/served")
         self._h_attn_mod = reg.histogram("attn_module_s")
         self._h_dense_mod = reg.histogram("dense_module_s")
         self._g_h2d_gbps = reg.gauge("xfer/h2d_gbps")
@@ -353,39 +410,11 @@ class InferenceEngine:
                           and T.supports_paged_decode(cfg))
         self.use_paged_prefill = (engine_cfg.prefill_mode == "paged"
                                   and T.supports_paged_prefill(cfg))
-        self._decode_fn = count_recompiles(jax.jit(
-            lambda p, c, t: T.decode_step(cfg, p, c, t)),
-            self._c_recompiles)
-        self._prefill_fn = count_recompiles(jax.jit(
-            lambda p, b: T.prefill(cfg, p, b, max_seq=engine_cfg.max_seq)),
-            self._c_recompiles)
-        # buffer donation lets XLA update the pool-shard pytrees in place:
-        # the pool dicts passed in are dead after the call, and the engine
-        # holds only the dicts each call returns (``kv.install_pools``).
-        donate = (1, 2)
-        # anchor / anchor-sink are static (baked into the trace); the
-        # exchange lane arrays (gd/gs/gt gathers, wd/ws_/wt writebacks)
-        # stage remote pool shards' pages through the anchor inside the
-        # same jitted call (see transformer.sharded_decode_step).
-        anchor, asink = self.kv.anchor, self.kv.sink
-        self._paged_fn = count_recompiles(jax.jit(
-            lambda p, kp, vp, gd, gs, gt, wd, ws_, wt, bt, ln, ws, wo, t,
-            pos: T.sharded_decode_step(
-                cfg, p, kp, vp, anchor, asink, gd, gs, gt, wd, ws_, wt,
-                bt, ln, ws, wo, t, pos),
-            donate_argnums=donate), self._c_recompiles)
-        self._chunk_fn = count_recompiles(jax.jit(
-            lambda p, kp, vp, gd, gs, gt, wd, wsb, wt, bt, ln, st, ws, wo,
-            t, li: T.sharded_prefill_chunk(
-                cfg, p, kp, vp, anchor, asink, gd, gs, gt, wd, wsb, wt,
-                bt, ln, st, ws, wo, t, li),
-            donate_argnums=donate), self._c_recompiles)
-        self._fused_fn = count_recompiles(jax.jit(
-            lambda p, kp, vp, gd, gs, gt, wd, wsb, wt, bt, ln, st, ws, wo,
-            t, li: T.sharded_fused_step(
-                cfg, p, kp, vp, anchor, asink, gd, gs, gt, wd, wsb, wt,
-                bt, ln, st, ws, wo, t, li),
-            donate_argnums=donate), self._c_recompiles)
+        jits = jitted_steps(cfg, engine_cfg.max_seq, self.kv.anchor,
+                            self.kv.sink)
+        (self._decode_fn, self._prefill_fn, self._paged_fn, self._chunk_fn,
+         self._fused_fn) = (count_recompiles(f, self._c_recompiles)
+                            for f in jits)
         self._decode_shapes: Set[Tuple[int, int, int]] = set()
         self._prefill_shapes: Set[Tuple[int, int, int, int]] = set()
         self._fused_shapes: Set[Tuple[int, int, int, int]] = set()
@@ -566,9 +595,21 @@ class InferenceEngine:
     # ------------------------------------------------------------------ admit
     def submit(self, req: Request) -> None:
         req.arrival = req.arrival or self.clock
+        req.submit_s = time.perf_counter()
         self.queue.append(req)
 
+    def _note_scheduled(self, reqs: Iterable[Request]) -> None:
+        """Count each request's wait from submit to the first step that
+        schedules a chunk of it (``queue/wait_s``, ``queue/served``) —
+        once: a preemption replay keeps the first wait."""
+        for r in reqs:
+            if r.queue_wait_s is None and r.submit_s is not None:
+                r.queue_wait_s = time.perf_counter() - r.submit_s
+                self._c_wait.inc(r.queue_wait_s)
+                self._c_served.inc()
+
     def _try_admit(self) -> List[Request]:
+        tr = self.tracer
         admitted = []
         while self.queue and (len(self.running) + len(self.prefilling)
                               < self.ecfg.max_batch):
@@ -585,14 +626,17 @@ class InferenceEngine:
                              head_dim=self.cfg.head_dim,
                              dtype_bytes=self._kv_itemsize,
                              arrival=req.arrival)
-            placement = dispatch_lp(self.workers, [ar])
+            with tr.span("lp", args={"rid": req.rid}):
+                placement = dispatch_lp(self.workers, [ar])
+                if placement is not None:
+                    apply_placement(self.workers, [ar], placement)
             if placement is None:
                 break
-            apply_placement(self.workers, [ar], placement)
             req.placement = placement[ar.rid]
             self.attn_reqs[req.rid] = ar
             # page allocation per kv group on assigned devices
-            ok = self._alloc_pages(req, ar)
+            with tr.span("alloc", args={"rid": req.rid}):
+                ok = self._alloc_pages(req, ar)
             if not ok:
                 release_request(self.workers, ar)
                 del self.attn_reqs[req.rid]
@@ -623,6 +667,7 @@ class InferenceEngine:
         # a PREEMPTED request resumes with prompt + generated tokens as the
         # prefill input (teacher-forcing: identical K/V and next-token
         # logits to the decode steps it replays, so resumption stays exact)
+        self._note_scheduled((req,))
         tokens = jnp.asarray(req.prompt + req.output, jnp.int32)[None]
         ctx = int(tokens.shape[1])
         with self.tracer.span("prefill", args={"rid": req.rid, "ctx": ctx}):
@@ -661,47 +706,53 @@ class InferenceEngine:
         if not rows:
             return
         cfg = self.cfg
-        Hkv, page = cfg.n_kv_heads, self.kv.page
-        chunk = self.ecfg.prefill_chunk
-        spans = [(r, full, min(chunk, len(full) - r.prefill_pos))
-                 for r, full in rows]
-        Bp = _bucket(len(spans))
-        Cp = _bucket(max(n for _, _, n in spans))
-        maxp = max(-(-(r.prefill_pos + n) // page) for r, _, n in spans)
-        Pp = _bucket(maxp)
-        sink = self.kv.sink
-        plan = self.kv.step_plan()
-        toks = np.zeros((Bp, Cp), np.int32)
-        starts = np.zeros((Bp,), np.int32)
-        lengths = np.zeros((Bp,), np.int32)
-        last_idx = np.zeros((Bp,), np.int32)
-        tables = np.full((Bp, Hkv, Pp), sink, np.int32)
-        wslots = np.full((Bp, Hkv, Cp), sink, np.int32)
-        woffs = np.zeros((Bp, Cp), np.int32)
-        for i, (r, full, n) in enumerate(spans):
-            s0 = r.prefill_pos
-            toks[i, :n] = full[s0:s0 + n]
-            starts[i] = s0
-            lengths[i] = s0 + n
-            last_idx[i] = n - 1
-            slots, offs = plan.scatter_indices(r.rid, s0, n)
-            wslots[i, :, :n] = slots
-            woffs[i, :n] = offs
-            # the chain covers the FULL prompt; the kernel only reads
-            # pages with base < lengths[i], so only those are staged from
-            # remote shards (anchor-local pages keep the full chain)
-            tables[i] = plan.block_table_matrix(r.rid, Pp,
-                                                n_tokens=s0 + n)
-        Gp = _bucket0(plan.gather_count)
-        exch = plan.exchange_arrays(Gp)
-        self._prefill_shapes.add((Bp, Cp, Pp, Gp))
-        host = exch + (tables, lengths, starts, wslots, woffs, toks,
-                       last_idx)
-        h2d = sum(a.nbytes for a in host)
-        dev = self._upload(host, h2d)
-        self._c_gather_d2d.inc(plan.d2d_bytes())
-        with self.tracer.span("prefill_chunk",
-                              args={"batch": Bp, "chunk": Cp, "pages": Pp}):
+        tr = self.tracer
+        with tr.span("plan"):
+            self._note_scheduled(r for r, _ in rows)
+            Hkv, page = cfg.n_kv_heads, self.kv.page
+            chunk = self.ecfg.prefill_chunk
+            spans = [(r, full, min(chunk, len(full) - r.prefill_pos))
+                     for r, full in rows]
+            Bp = _bucket(len(spans))
+            Cp = _bucket(max(n for _, _, n in spans))
+            maxp = max(-(-(r.prefill_pos + n) // page)
+                       for r, _, n in spans)
+            Pp = _bucket(maxp)
+            sink = self.kv.sink
+            plan = self.kv.step_plan()
+            toks = np.zeros((Bp, Cp), np.int32)
+            starts = np.zeros((Bp,), np.int32)
+            lengths = np.zeros((Bp,), np.int32)
+            last_idx = np.zeros((Bp,), np.int32)
+            tables = np.full((Bp, Hkv, Pp), sink, np.int32)
+            wslots = np.full((Bp, Hkv, Cp), sink, np.int32)
+            woffs = np.zeros((Bp, Cp), np.int32)
+            for i, (r, full, n) in enumerate(spans):
+                s0 = r.prefill_pos
+                toks[i, :n] = full[s0:s0 + n]
+                starts[i] = s0
+                lengths[i] = s0 + n
+                last_idx[i] = n - 1
+                slots, offs = plan.scatter_indices(r.rid, s0, n)
+                wslots[i, :, :n] = slots
+                woffs[i, :n] = offs
+                # the chain covers the FULL prompt; the kernel only reads
+                # pages with base < lengths[i], so only those are staged
+                # from remote shards (anchor-local pages keep the full
+                # chain)
+                tables[i] = plan.block_table_matrix(r.rid, Pp,
+                                                    n_tokens=s0 + n)
+            Gp = _bucket0(plan.gather_count)
+            exch = plan.exchange_arrays(Gp)
+            self._prefill_shapes.add((Bp, Cp, Pp, Gp))
+            host = exch + (tables, lengths, starts, wslots, woffs, toks,
+                           last_idx)
+            h2d = sum(a.nbytes for a in host)
+            self._c_gather_d2d.inc(plan.d2d_bytes())
+        with tr.span("upload"):
+            dev = self._upload(host, h2d)
+        with tr.span("prefill_chunk",
+                     args={"batch": Bp, "chunk": Cp, "pages": Pp}):
             if self._trace_modules:
                 a0, d0 = self._probe_totals()
                 kps, vps = self.kv.pools()
@@ -725,23 +776,24 @@ class InferenceEngine:
         self._c_chunks.inc()
         self.clock += self._model_prefill_time(
             sum(n for _, _, n in spans))
-        nxt = None
-        for i, (r, full, n) in enumerate(spans):
-            r.prefill_pos += n
-            if r.prefill_pos < len(full):
-                continue
-            if nxt is None:             # logits pulled once, on demand
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-                self._c_d2h.inc(logits.nbytes)
-            r.output.append(int(nxt[i]))
-            r.state = RequestState.RUNNING
-            self.prefilling.remove(r)
-            self.running.append(r)
-            if r.ttft is None:
-                r.ttft = self.clock - r.arrival
-                self._h_ttft.observe(r.ttft)
-            if r.done:      # max_new_tokens == 1, or resume filled the last
-                self._finish(r)
+        with tr.span("readback"):
+            nxt = None
+            for i, (r, full, n) in enumerate(spans):
+                r.prefill_pos += n
+                if r.prefill_pos < len(full):
+                    continue
+                if nxt is None:             # logits pulled once, on demand
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                    self._c_d2h.inc(logits.nbytes)
+                r.output.append(int(nxt[i]))
+                r.state = RequestState.RUNNING
+                self.prefilling.remove(r)
+                self.running.append(r)
+                if r.ttft is None:
+                    r.ttft = self.clock - r.arrival
+                    self._h_ttft.observe(r.ttft)
+                if r.done:  # max_new_tokens == 1, or resume filled the last
+                    self._finish(r)
 
     # ----------------------------------------------------------------- decode
     def _decode_batch(self) -> None:
@@ -780,41 +832,43 @@ class InferenceEngine:
     def _decode_batch_paged(self, reqs: List[Request]) -> None:
         """Fast path: block tables + device-resident pools, no gather."""
         cfg = self.cfg
-        Hkv, page = cfg.n_kv_heads, self.kv.page
-        active = self._reserve_decode_rows(reqs)
-        if not active:
-            return
-        B = len(active)
-        Bp = _bucket(B)
-        maxp = max(-(-r.ctx_len // page) for r in active)
-        Pp = _bucket(maxp)
-        sink = self.kv.sink
-        plan = self.kv.step_plan()
-        tables = np.full((Bp, Hkv, Pp), sink, np.int32)
-        lengths = np.zeros((Bp,), np.int32)
-        wslot = np.full((Bp, Hkv), sink, np.int32)
-        woff = np.zeros((Bp,), np.int32)
-        pos = np.zeros((Bp,), np.int32)
-        toks = np.zeros((Bp, 1), np.int32)
-        for i, r in enumerate(active):
-            p_new = r.ctx_len - 1
-            tables[i] = plan.block_table_matrix(r.rid, Pp,
-                                                n_tokens=p_new + 1)
-            slots, offs = plan.scatter_indices(r.rid, p_new, 1)
-            wslot[i] = slots[:, 0]
-            lengths[i] = p_new + 1
-            woff[i] = offs[0]
-            pos[i] = p_new
-            toks[i, 0] = r.output[-1]
-        Gp = _bucket0(plan.gather_count)
-        exch = plan.exchange_arrays(Gp)
-        self._decode_shapes.add((Bp, Pp, Gp))
-        host = exch + (tables, lengths, wslot, woff, toks, pos)
-        h2d = sum(a.nbytes for a in host)
-        dev = self._upload(host, h2d)
-        self._c_gather_d2d.inc(plan.d2d_bytes())
-        with self.tracer.span("paged_decode",
-                              args={"batch": Bp, "pages": Pp}):
+        tr = self.tracer
+        with tr.span("plan"):
+            Hkv, page = cfg.n_kv_heads, self.kv.page
+            active = self._reserve_decode_rows(reqs)
+            if not active:
+                return
+            B = len(active)
+            Bp = _bucket(B)
+            maxp = max(-(-r.ctx_len // page) for r in active)
+            Pp = _bucket(maxp)
+            sink = self.kv.sink
+            plan = self.kv.step_plan()
+            tables = np.full((Bp, Hkv, Pp), sink, np.int32)
+            lengths = np.zeros((Bp,), np.int32)
+            wslot = np.full((Bp, Hkv), sink, np.int32)
+            woff = np.zeros((Bp,), np.int32)
+            pos = np.zeros((Bp,), np.int32)
+            toks = np.zeros((Bp, 1), np.int32)
+            for i, r in enumerate(active):
+                p_new = r.ctx_len - 1
+                tables[i] = plan.block_table_matrix(r.rid, Pp,
+                                                    n_tokens=p_new + 1)
+                slots, offs = plan.scatter_indices(r.rid, p_new, 1)
+                wslot[i] = slots[:, 0]
+                lengths[i] = p_new + 1
+                woff[i] = offs[0]
+                pos[i] = p_new
+                toks[i, 0] = r.output[-1]
+            Gp = _bucket0(plan.gather_count)
+            exch = plan.exchange_arrays(Gp)
+            self._decode_shapes.add((Bp, Pp, Gp))
+            host = exch + (tables, lengths, wslot, woff, toks, pos)
+            h2d = sum(a.nbytes for a in host)
+            self._c_gather_d2d.inc(plan.d2d_bytes())
+        with tr.span("upload"):
+            dev = self._upload(host, h2d)
+        with tr.span("paged_decode", args={"batch": Bp, "pages": Pp}):
             if self._trace_modules:
                 a0, d0 = self._probe_totals()
                 kps, vps = self.kv.pools()
@@ -833,16 +887,17 @@ class InferenceEngine:
             self.tracer.sync(logits)
         self._c_model_calls.inc()
         self._c_h2d.inc(h2d)
-        nxt = np.asarray(jnp.argmax(logits[:B], axis=-1), np.int32)
-        self._c_d2h.inc(logits.nbytes)
-        for r in active:
-            # the reservation above already advanced kv.lengths; the jitted
-            # step scattered the token K/V into those pages on device
-            grow_context(self.workers, self.attn_reqs[r.rid], 1)
-        for i, r in enumerate(active):
-            r.output.append(int(nxt[i]))
-            if r.done:
-                self._finish(r)
+        with tr.span("readback"):
+            nxt = np.asarray(jnp.argmax(logits[:B], axis=-1), np.int32)
+            self._c_d2h.inc(logits.nbytes)
+            for r in active:
+                # the reservation above already advanced kv.lengths; the
+                # jitted step scattered the token K/V into those pages
+                grow_context(self.workers, self.attn_reqs[r.rid], 1)
+            for i, r in enumerate(active):
+                r.output.append(int(nxt[i]))
+                if r.done:
+                    self._finish(r)
 
     def _decode_batch_dense(self, reqs: List[Request]) -> None:
         """Reference path: gather pages into a dense host-side cache,
@@ -903,66 +958,73 @@ class InferenceEngine:
         one (it joins ``running`` after this call instead of decoding in
         the same iteration's second call)."""
         cfg = self.cfg
-        Hkv, page = cfg.n_kv_heads, self.kv.page
-        # reserve decode capacity FIRST: §5.3 handling inside may preempt
-        # prefilling requests, which must not be in this step's row batch
-        dec = self._reserve_decode_rows(
-            [r for r in self.running if not r.done])
-        budget = self.ecfg.token_budget or (self.ecfg.max_batch
-                                            + self.ecfg.prefill_chunk)
-        left = budget - len(dec)        # decode rows always admitted
-        spans: List[Tuple[Request, List[int], int]] = []
-        for r in self.prefilling:
-            if left <= 0:
-                break
-            full = r.prompt + r.output
-            n = min(self._chunk_now, len(full) - r.prefill_pos, left)
-            if n <= 0:
-                break
-            spans.append((r, full, n))
-            left -= n
-        if not dec and not spans:
-            return
-        rows = ([(r.rid, r.ctx_len - 1, 1) for r in dec]
-                + [(r.rid, r.prefill_pos, n) for r, _, n in spans])
-        B = len(rows)
-        Bp = _bucket(B)
-        Cp = _bucket(max(n for _, _, n in rows))
-        maxp = max(-(-(s + n) // page) for _, s, n in rows)
-        Pp = _bucket(maxp)
-        sink = self.kv.sink
-        plan = self.kv.step_plan()
-        toks = np.zeros((Bp, Cp), np.int32)
-        starts = np.zeros((Bp,), np.int32)
-        lengths = np.zeros((Bp,), np.int32)
-        last_idx = np.zeros((Bp,), np.int32)
-        tables = np.full((Bp, Hkv, Pp), sink, np.int32)
-        ws, wo = plan.mixed_scatter_indices(rows, Cp)
-        wslots = np.full((Bp, Hkv, Cp), sink, np.int32)
-        woffs = np.zeros((Bp, Cp), np.int32)
-        wslots[:B] = ws
-        woffs[:B] = wo
-        for i, (rid, s0, n) in enumerate(rows):
-            starts[i] = s0
-            lengths[i] = s0 + n
-            last_idx[i] = n - 1
-            # the chain covers the FULL prompt; the kernel only reads
-            # pages with base < lengths[i], so only those are staged from
-            # remote shards (anchor-local pages keep the full chain)
-            tables[i] = plan.block_table_matrix(rid, Pp, n_tokens=s0 + n)
-        for i, r in enumerate(dec):
-            toks[i, 0] = r.output[-1]
-        for j, (r, full, n) in enumerate(spans):
-            toks[len(dec) + j, :n] = full[r.prefill_pos:r.prefill_pos + n]
-        Gp = _bucket0(plan.gather_count)
-        exch = plan.exchange_arrays(Gp)
-        self._fused_shapes.add((Bp, Cp, Pp, Gp))
-        host = exch + (tables, lengths, starts, wslots, woffs, toks,
-                       last_idx)
-        h2d = sum(a.nbytes for a in host)
-        dev = self._upload(host, h2d)
-        self._c_gather_d2d.inc(plan.d2d_bytes())
         tr = self.tracer
+        with tr.span("plan"):
+            Hkv, page = cfg.n_kv_heads, self.kv.page
+            # reserve decode capacity FIRST: §5.3 handling inside may
+            # preempt prefilling requests, which must not be in this
+            # step's row batch
+            dec = self._reserve_decode_rows(
+                [r for r in self.running if not r.done])
+            budget = self.ecfg.token_budget or (self.ecfg.max_batch
+                                                + self.ecfg.prefill_chunk)
+            left = budget - len(dec)        # decode rows always admitted
+            spans: List[Tuple[Request, List[int], int]] = []
+            for r in self.prefilling:
+                if left <= 0:
+                    break
+                full = r.prompt + r.output
+                n = min(self._chunk_now, len(full) - r.prefill_pos, left)
+                if n <= 0:
+                    break
+                spans.append((r, full, n))
+                left -= n
+            if not dec and not spans:
+                return
+            self._note_scheduled(r for r, _, _ in spans)
+            rows = ([(r.rid, r.ctx_len - 1, 1) for r in dec]
+                    + [(r.rid, r.prefill_pos, n) for r, _, n in spans])
+            B = len(rows)
+            Bp = _bucket(B)
+            Cp = _bucket(max(n for _, _, n in rows))
+            maxp = max(-(-(s + n) // page) for _, s, n in rows)
+            Pp = _bucket(maxp)
+            sink = self.kv.sink
+            plan = self.kv.step_plan()
+            toks = np.zeros((Bp, Cp), np.int32)
+            starts = np.zeros((Bp,), np.int32)
+            lengths = np.zeros((Bp,), np.int32)
+            last_idx = np.zeros((Bp,), np.int32)
+            tables = np.full((Bp, Hkv, Pp), sink, np.int32)
+            ws, wo = plan.mixed_scatter_indices(rows, Cp)
+            wslots = np.full((Bp, Hkv, Cp), sink, np.int32)
+            woffs = np.zeros((Bp, Cp), np.int32)
+            wslots[:B] = ws
+            woffs[:B] = wo
+            for i, (rid, s0, n) in enumerate(rows):
+                starts[i] = s0
+                lengths[i] = s0 + n
+                last_idx[i] = n - 1
+                # the chain covers the FULL prompt; the kernel only reads
+                # pages with base < lengths[i], so only those are staged
+                # from remote shards (anchor-local pages keep the full
+                # chain)
+                tables[i] = plan.block_table_matrix(rid, Pp,
+                                                    n_tokens=s0 + n)
+            for i, r in enumerate(dec):
+                toks[i, 0] = r.output[-1]
+            for j, (r, full, n) in enumerate(spans):
+                toks[len(dec) + j, :n] = full[r.prefill_pos:
+                                              r.prefill_pos + n]
+            Gp = _bucket0(plan.gather_count)
+            exch = plan.exchange_arrays(Gp)
+            self._fused_shapes.add((Bp, Cp, Pp, Gp))
+            host = exch + (tables, lengths, starts, wslots, woffs, toks,
+                           last_idx)
+            h2d = sum(a.nbytes for a in host)
+            self._c_gather_d2d.inc(plan.d2d_bytes())
+        with tr.span("upload"):
+            dev = self._upload(host, h2d)
         n_pre = sum(n for _, _, n in spans)
         # timing the step for the autotuner costs a device sync, so only
         # pay it when an SLO is configured (the eager probe already syncs)
@@ -972,7 +1034,7 @@ class InferenceEngine:
                                          "pages": Pp,
                                          "decode_rows": len(dec),
                                          "prefill_tokens": n_pre}):
-            t0 = time.perf_counter() if (tr.enabled or time_it) else 0.0
+            t0 = time.perf_counter() if time_it else 0.0
             if self._trace_modules:
                 a0, d0 = self._probe_totals()
                 kps, vps = self.kv.pools()
@@ -990,20 +1052,10 @@ class InferenceEngine:
                     self.params, kps, vps, *dev)
                 self.kv.install_pools(kps, vps)
             tr.sync(logits)
-            if tr.enabled or time_it:
-                if not tr.enabled:          # sync() above was a no-op
-                    jax.block_until_ready(logits)
-                dt = time.perf_counter() - t0
-                if tr.enabled:
-                    # attribute the ONE measured call to its phases by
-                    # token share — both phases ran inside a single jit
-                    tr.add_phase_spans(
-                        "fused/", t0, dt,
-                        {"decode": float(len(dec)),
-                         "prefill": float(n_pre)},
-                        depth=len(tr._stack))
-                if time_it and self._c_recompiles.value == rc0:
-                    self._autotune_chunk(dt)
+            if time_it:
+                jax.block_until_ready(logits)
+                if self._c_recompiles.value == rc0:
+                    self._autotune_chunk(time.perf_counter() - t0)
         self._c_model_calls.inc()
         self._c_fused.inc()
         self._c_h2d.inc(h2d)
@@ -1011,29 +1063,30 @@ class InferenceEngine:
             self._c_pre_h2d.inc(h2d)
             self._c_chunks.inc()
             self.clock += self._model_prefill_time(n_pre)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        self._c_d2h.inc(logits.nbytes)
-        for r in dec:
-            # the reservation already advanced kv.lengths; the jitted
-            # step scattered the token K/V into those pages on device
-            grow_context(self.workers, self.attn_reqs[r.rid], 1)
-        for i, r in enumerate(dec):
-            r.output.append(int(nxt[i]))
-            if r.done:
-                self._finish(r)
-        for j, (r, full, n) in enumerate(spans):
-            r.prefill_pos += n
-            if r.prefill_pos < len(full):
-                continue
-            r.output.append(int(nxt[len(dec) + j]))
-            r.state = RequestState.RUNNING
-            self.prefilling.remove(r)
-            self.running.append(r)
-            if r.ttft is None:
-                r.ttft = self.clock - r.arrival
-                self._h_ttft.observe(r.ttft)
-            if r.done:      # max_new_tokens == 1, or resume filled the last
-                self._finish(r)
+        with tr.span("readback"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            self._c_d2h.inc(logits.nbytes)
+            for r in dec:
+                # the reservation already advanced kv.lengths; the jitted
+                # step scattered the token K/V into those pages on device
+                grow_context(self.workers, self.attn_reqs[r.rid], 1)
+            for i, r in enumerate(dec):
+                r.output.append(int(nxt[i]))
+                if r.done:
+                    self._finish(r)
+            for j, (r, full, n) in enumerate(spans):
+                r.prefill_pos += n
+                if r.prefill_pos < len(full):
+                    continue
+                r.output.append(int(nxt[len(dec) + j]))
+                r.state = RequestState.RUNNING
+                self.prefilling.remove(r)
+                self.running.append(r)
+                if r.ttft is None:
+                    r.ttft = self.clock - r.arrival
+                    self._h_ttft.observe(r.ttft)
+                if r.done:  # max_new_tokens == 1, or resume filled the last
+                    self._finish(r)
 
     def _autotune_chunk(self, warm_s: float) -> None:
         """Feed one warm (recompile-free) fused-step wall latency to the
@@ -1110,26 +1163,29 @@ class InferenceEngine:
                     if r.rid == rid), None)
         if req is None:
             return
-        old = req.placement
-        req.placement = dict(new_placement)
-        # Move group chains to their new devices by cross-pool copy.  Only
-        # bytes that PHYSICALLY moved are metered and handed to the hauler
-        # (as per-source-device tasks debited against the compute-overlap
-        # window in step()); an all-or-nothing refusal (destination shard
-        # full) is surfaced instead of silently booked.
-        moved_bytes = 0.0
-        tasks: List[MigrationTask] = []
-        incomplete = 0
-        for grp, dev in self._group_devices(req):
-            res = self.kv.migrate_group(rid, grp, dev)
-            if not res.complete:
-                incomplete += 1
-                continue
-            moved_bytes += res.nbytes
-            for src, pages in res.by_src.items():
-                tasks.append(MigrationTask(
-                    rid, src, dev, heads=float(self.cfg.gqa_ratio),
-                    nbytes=float(pages * self.kv.bytes_per_slot())))
+        with self.tracer.span("migrate", args={"rid": rid}) as sp:
+            req.placement = dict(new_placement)
+            # Move group chains to their new devices by cross-pool copy.
+            # Only bytes that PHYSICALLY moved are metered and handed to
+            # the hauler (as per-source-device tasks debited against the
+            # compute-overlap window in step()); an all-or-nothing refusal
+            # (destination shard full) is surfaced instead of silently
+            # booked.
+            moved_bytes = 0.0
+            tasks: List[MigrationTask] = []
+            incomplete = copied = 0
+            for grp, dev in self._group_devices(req):
+                res = self.kv.migrate_group(rid, grp, dev)
+                if not res.complete:
+                    incomplete += 1
+                    continue
+                copied += res.moved > 0
+                moved_bytes += res.nbytes
+                for src, pages in res.by_src.items():
+                    tasks.append(MigrationTask(
+                        rid, src, dev, heads=float(self.cfg.gqa_ratio),
+                        nbytes=float(pages * self.kv.bytes_per_slot())))
+            sp.set(groups=copied, bytes=moved_bytes)
         if incomplete:
             self._c_migr_partial.inc(incomplete)
             warnings.warn(
@@ -1146,7 +1202,6 @@ class InferenceEngine:
     # ------------------------------------------------------------------- step
     def step(self) -> Dict[str, float]:
         tr = self.tracer
-        t_wall = time.perf_counter() if tr.enabled else 0.0
         with tr.span("step"):
             with tr.span("admit"):
                 admitted = self._try_admit()
@@ -1169,22 +1224,17 @@ class InferenceEngine:
             # Θ-triggered rebalance (at most one request per step, §5.3);
             # once the module probe has attributed measured attention time,
             # the dispatcher recalibrates from the snapshot first
-            snap = (self.snapshot(ATTN_SNAPSHOT_PREFIX)
-                    if self._measured_attn else None)
-            d = maybe_rebalance(self.workers, list(self.attn_reqs.values()),
-                                theta=self.ecfg.theta, snapshot=snap)
-            if d is not None:
-                with tr.span("rebalance", args={"rid": d.request.rid}):
+            with tr.span("rebalance") as sp:
+                snap = (self.snapshot(ATTN_SNAPSHOT_PREFIX)
+                        if self._measured_attn else None)
+                d = maybe_rebalance(self.workers,
+                                    list(self.attn_reqs.values()),
+                                    theta=self.ecfg.theta, snapshot=snap)
+                if d is not None:
+                    sp.set(rid=d.request.rid)
                     self._apply_migration(d.request.rid, d.new_placement)
-                self._c_redisp.inc()
-            attn_t, dense_t = self._model_decode_parts()
-            step_time = attn_t + dense_t
-            if tr.enabled:
-                # modeled module spans on the simulated-clock track
-                tr.add_span("attention_model", self.clock, attn_t,
-                            track="sim")
-                tr.add_span("dense_model", self.clock + attn_t, dense_t,
-                            track="sim")
+                    self._c_redisp.inc()
+            step_time = self._model_decode_time()
             # migrations ride in the dense-compute overlap window (§6);
             # the link model follows the measured h2d bandwidth gauge
             if self._g_h2d_gbps.value > 0.0:
@@ -1192,8 +1242,6 @@ class InferenceEngine:
             self.hauler.advance(step_time * self.ecfg.migration_overlap)
             self.clock += step_time
             self._c_steps.inc()
-        if tr.enabled:
-            self._h_step.observe(time.perf_counter() - t_wall)
         return {"clock": self.clock, "running": len(self.running),
                 "prefilling": len(self.prefilling),
                 "queued": len(self.queue)}

@@ -32,6 +32,11 @@ class Request:
     ttft: Optional[float] = None
     finish_time: Optional[float] = None
     prefill_start: Optional[float] = None
+    # wall clock (perf_counter) at submit, and seconds from then to the
+    # step that first scheduled a chunk of it; a preemption replay keeps
+    # both
+    submit_s: Optional[float] = None
+    queue_wait_s: Optional[float] = None
 
     @property
     def ctx_len(self) -> int:

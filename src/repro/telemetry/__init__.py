@@ -3,8 +3,9 @@
 Hetis's online load-dispatching policy rebalances Attention head placement
 from live latency/memory signals; this package provides those signals:
 
-  * :class:`Tracer` — nested spans (wall-clock or explicit simulated
-    timelines), ring-buffered, exportable as Chrome ``trace_event`` JSON.
+  * :class:`Tracer` — nested spans (wall-clock, each also a profiler
+    annotation ``engine.<name>``, or explicit simulated timelines),
+    ring-buffered, exportable as Chrome ``trace_event`` JSON.
   * :class:`MetricsRegistry` — counters / gauges / histograms with lazy
     percentiles and EWMA smoothing; ``snapshot()`` feeds the dispatcher,
     hauler, and cost model with *measured* values.

@@ -1,10 +1,11 @@
 """Tracer: nested wall-clock spans, ring-buffered, Chrome-trace exportable.
 
 The engine's control loop is host-driven Python around a handful of jitted
-calls, so host-side spans capture exactly the boundaries that matter for
-the online dispatcher: admit / prefill_chunk / paged_decode / rebalance,
-plus per-module Attention/MLP spans when the engine runs its eager
-instrumented probe (``transformer.paged_decode_step_traced``).
+calls, so host-side spans capture exactly the boundaries that matter: the
+step, admission (the dispatcher's LP, page allocation), the step plan, the
+upload, the jitted call, the read-back and migrations, plus per-module
+Attention/MLP spans when the engine runs its eager instrumented probe
+(``transformer.paged_decode_step_traced``).
 
 Design constraints:
 
@@ -16,11 +17,18 @@ Design constraints:
     ring buffer; aggregate per-name duration/count totals survive ring
     overflow (the dispatcher and the profiler fit consume totals and
     recent spans, not unbounded history).
+  * **The profiler's clock.**  A tracer built with a ``profiler_prefix``
+    also opens a ``jax.profiler.TraceAnnotation`` named
+    ``<prefix><name>`` with the span's args as its metadata for each
+    context-manager span, so under ``jax.profiler.trace`` it lands on the
+    calling thread's host line of the ``.xplane.pb``, on the same clock as
+    the device's ops (the engine's ``engine.*``; see
+    ``docs/observability.md``).  Without a prefix nothing is annotated.
   * **Two time bases.**  Context-manager spans use the wall clock
     (``time.perf_counter``, optionally device-sync'd via ``sync()``);
-    ``add_span`` records spans on explicit timelines — the engine and the
-    DES place *simulated-clock* module spans on their own track, which the
-    Chrome export maps to a separate pid so Perfetto renders both.
+    ``add_span`` records spans on explicit timelines — the DES places
+    *simulated-clock* module spans on their own track, which the Chrome
+    export maps to a separate pid so Perfetto renders both.
 
 Export: ``export_chrome()`` / ``write_chrome()`` produce Chrome
 ``trace_event`` JSON ("X" complete events) loadable in chrome://tracing or
@@ -33,6 +41,8 @@ from __future__ import annotations
 import collections
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span(NamedTuple):
@@ -59,12 +69,15 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]):
@@ -76,24 +89,42 @@ class _SpanCtx:
         tr = self.tracer
         self.depth = len(tr._stack)
         tr._stack.append(self)
+        self.ann = None
+        if tr.profiler_prefix is not None:
+            self.ann = TraceAnnotation(tr.profiler_prefix + self.name,
+                                       **(self.args or {}))
+            self.ann.__enter__()
         self.t0 = tr._time()
         return self
 
     def __exit__(self, *exc):
         tr = self.tracer
         dur = tr._time() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         tr._stack.pop()
         tr._record(Span(self.name, self.t0, dur, self.depth, "main",
                         self.args))
         return False
 
+    def set(self, **args) -> None:
+        """Add args known only once the span's work is done (a
+        migration's groups and bytes) to the span and its annotation."""
+        self.args = {**(self.args or {}), **args}
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+
 
 class Tracer:
-    """Nested-span tracer with a ring buffer and per-name totals."""
+    """Nested-span tracer with a ring buffer and per-name totals;
+    ``profiler_prefix`` names its spans' profiler annotations (None: no
+    annotation)."""
 
     def __init__(self, enabled: bool = False, capacity: int = 65536,
-                 time_fn=time.perf_counter):
+                 time_fn=time.perf_counter,
+                 profiler_prefix: Optional[str] = None):
         self.enabled = enabled
+        self.profiler_prefix = profiler_prefix
         self._time = time_fn
         self.events: collections.deque = collections.deque(maxlen=capacity)
         self._stack: List[_SpanCtx] = []
@@ -103,8 +134,9 @@ class Tracer:
 
     # ------------------------------------------------------------- recording
     def span(self, name: str, args: Optional[Dict[str, Any]] = None):
-        """Context manager timing a nested wall-clock span.  On a disabled
-        tracer this returns a shared no-op object (no allocation)."""
+        """Context manager timing a nested wall-clock span, annotated for
+        the profiler where the tracer has a prefix.  On a disabled tracer this returns a shared
+        no-op object (no allocation, no clock read)."""
         if not self.enabled:
             return _NULL_SPAN
         return _SpanCtx(self, name, args)
@@ -121,33 +153,10 @@ class Tracer:
                  depth: int = 0, args: Optional[Dict[str, Any]] = None
                  ) -> None:
         """Record a span with an explicit (ts, dur) on an explicit track —
-        used for simulated-clock timelines (engine sim clock, DES)."""
+        used for simulated-clock timelines (the DES); never annotated."""
         if not self.enabled:
             return
         self._record(Span(name, ts, dur, depth, track, args))
-
-    def add_phase_spans(self, prefix: str, ts: float, dur: float,
-                        weights: Dict[str, float], track: str = "main",
-                        depth: int = 0,
-                        args: Optional[Dict[str, Any]] = None) -> None:
-        """Attribute ONE measured span to several phases: split ``[ts,
-        ts + dur)`` into consecutive ``<prefix><phase>`` child spans whose
-        durations are proportional to ``weights`` (zero-weight phases are
-        skipped).  Used by the engine's fused prefill+decode step, where
-        both phases execute inside a single jitted call and only their
-        token shares are known."""
-        if not self.enabled:
-            return
-        total = sum(w for w in weights.values() if w > 0.0)
-        if total <= 0.0:
-            return
-        t = ts
-        for phase, w in weights.items():
-            if w <= 0.0:
-                continue
-            d = dur * w / total
-            self._record(Span(f"{prefix}{phase}", t, d, depth, track, args))
-            t += d
 
     def _record(self, sp: Span) -> None:
         self.events.append(sp)

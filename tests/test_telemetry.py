@@ -2,6 +2,8 @@
 engine's instrumented spans, the recompile tripwire, and the measured-
 snapshot calibration that flips redispatch decisions."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,6 +109,37 @@ def test_disabled_tracer_is_shared_noop():
     assert len(tr) == 0 and tr.count("x") == 0
 
 
+def test_profiler_annotation_only_with_a_prefix(monkeypatch):
+    """Spans are profiler annotations named ``<prefix><name>`` only on a
+    tracer built with a prefix (the engine's ``engine.``); the telemetry
+    layer names no caller."""
+    import repro.telemetry.tracer as tracer_mod
+    opened = []
+
+    class Ann:
+        def __init__(self, name, **args):
+            opened.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **args):
+            opened.append(("set", args))
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Ann)
+    with Tracer(enabled=True).span("x", args={"k": 1}) as sp:
+        sp.set(n=2)
+    assert opened == []
+    tr = Tracer(enabled=True, profiler_prefix="engine.")
+    with tr.span("x", args={"k": 1}) as sp:
+        sp.set(n=2)
+    assert opened == [("engine.x", {"k": 1}), ("set", {"n": 2})]
+    assert tr.spans("x")[0].args == {"k": 1, "n": 2}
+
+
 def test_ring_buffer_totals_survive_overflow():
     tr = Tracer(enabled=True, capacity=4)
     for i in range(10):
@@ -177,20 +210,26 @@ def test_validator_rejects_malformed_traces():
 def test_engine_trace_has_nested_module_spans(traced_engine):
     tr = traced_engine.tracer
     names = {s.name for s in tr.spans()}
-    assert {"step", "admit", "fused_step", "fused/decode",
-            "fused/prefill", "attention", "mlp"} <= names
+    assert {"step", "admit", "lp", "alloc", "plan", "upload", "fused_step",
+            "readback", "rebalance", "attention", "mlp"} <= names
     assert all(s.depth == 0 for s in tr.spans("step"))
-    assert all(s.depth == 1 for s in tr.spans("fused_step"))
-    # the per-phase attribution splits each fused call's window
-    for phase in ("fused/decode", "fused/prefill"):
-        assert all(s.depth >= 2 for s in tr.spans(phase))
+    for name in ("admit", "plan", "upload", "fused_step", "readback",
+                 "rebalance"):
+        assert tr.spans(name)
+        assert all(s.depth == 1 for s in tr.spans(name)), name
+    # admission's LP and page allocation name the request they serve
+    for name in ("lp", "alloc"):
+        assert len(tr.spans(name)) == 3
+        assert all(s.depth == 2 and s.args["rid"] in (0, 1, 2)
+                   for s in tr.spans(name)), name
     # module spans nest below the fused span they ran in
     assert all(s.depth >= 2 for s in tr.spans("attention", track="main"))
     # attention spans carry the (h, g) annotation the profiler fit reads
     assert all("heads" in s.args for s in tr.spans("attention"))
-    # modeled module spans live on the simulated-clock track
-    assert tr.spans("attention_model", track="sim")
-    assert tr.spans("dense_model", track="sim")
+    # the live engine records no modeled-clock spans
+    assert not tr.spans(track="sim")
+    assert not names & {"fused/decode", "fused/prefill", "attention_model",
+                        "dense_model"}
 
 
 def test_profiler_fit_consumes_engine_spans(traced_engine):
@@ -218,7 +257,12 @@ def test_snapshot_exposes_latency_and_occupancy(traced_engine):
     snap = traced_engine.snapshot()
     assert snap["ttft_s/p95"] >= snap["ttft_s/p50"] > 0
     assert snap["tpot_s/count"] > 0
-    assert snap["step_latency_s/count"] > 0
+    # the step's wall time is the ``step`` span's, not a histogram
+    assert not any(k.startswith("step_latency_s") for k in snap)
+    # every request waited once from submit to its first chunk
+    assert snap["queue/served"] == 3
+    assert 0 < snap["queue/wait_s"] == pytest.approx(
+        sum(r.queue_wait_s for r in traced_engine.finished))
     assert "kv/occupancy" in snap
     assert any(k.startswith("kv/device/") and k.endswith("used_bytes")
                for k in snap)
@@ -286,6 +330,76 @@ def test_redispatch_flips_on_measured_snapshot():
     assert d is not None
     assert d.new_placement.get(0, 0) < 4
     assert workers[0].calib > workers[1].calib
+
+
+def _migrating_run(eng, prompts, annotate=None):
+    """Submit ``prompts``, step the engine, and move the first running
+    request's every head group to a pool device that does not hold them
+    all at the second step boundary; returns the migrated rid.
+    ``annotate`` wraps each step (e.g. a profiler annotation)."""
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
+    wrap = annotate or (lambda: contextlib.nullcontext())
+    moved, n = None, 0
+    while eng.queue or eng.running or eng.prefilling:
+        with wrap():
+            if n == 2 and eng.running:
+                r = eng.running[0]
+                dst = 1 if r.placement.get(1, 0) < CFG.n_heads else 2
+                eng._apply_migration(r.rid, {dst: CFG.n_heads})
+                moved = r.rid
+            eng.step()
+        n += 1
+        assert n < 200
+    return moved
+
+
+def test_disabled_tracer_records_nothing_and_allocates_no_span(
+        monkeypatch):
+    """With telemetry off every span call site of the engine — admission,
+    plan, upload, the jitted call, read-back, rebalance, migration — gets
+    the shared no-op span: no span object, no profiler annotation, nothing
+    recorded.  The wall-clock counters still count."""
+    import repro.telemetry.tracer as tracer_mod
+
+    def forbidden(*a, **k):
+        raise AssertionError("span object allocated with tracing off")
+
+    monkeypatch.setattr(tracer_mod, "_SpanCtx", forbidden)
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", forbidden)
+    eng = make_engine()
+    moved = _migrating_run(eng, random_prompts(3, seed=2))
+    assert moved is not None and len(eng.finished) == 3
+    assert len(eng.tracer) == 0 and not eng.tracer.totals
+    snap = eng.snapshot()
+    assert snap["queue/served"] == 3 and snap["queue/wait_s"] > 0
+    assert snap["migrated_bytes"] > 0
+
+
+def test_queue_wait_stamped_once_across_preemption_replay():
+    """``queue/wait_s`` runs from submit to the first step that schedules
+    a chunk of the request; a preempted request replays its prompt but
+    keeps its first wait, and counts once."""
+    eng = make_engine()
+    prompt = random_prompts(1, seed=4)[0]
+    eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=6))
+    eng.step()
+    (r,) = eng.running + eng.prefilling
+    wait = r.queue_wait_s
+    assert wait is not None and wait > 0
+    snap = eng.snapshot()
+    assert snap["queue/served"] == 1
+    assert snap["queue/wait_s"] == pytest.approx(wait)
+    eng.step()
+    eng._preempt(r)
+    assert r in eng.queue and r.prefill_pos == 0
+    eng.run_until_drained()
+    assert eng.finished == [r] and r.queue_wait_s == wait
+    snap = eng.snapshot()
+    assert snap["queue/served"] == 1
+    assert snap["queue/wait_s"] == pytest.approx(wait)
+    assert snap["evictions"] == 1
+    assert r.output == ref_decode(prompt, 6)
 
 
 # ------------------------------------------------------------- sim tracer

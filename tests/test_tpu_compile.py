@@ -3,7 +3,8 @@
 The TPU compiler, which is installed even where no chip is, compiles for a
 described v5e: both paged kernels at the head shapes of the served archs
 in bf16, and the full-width qwen1.5-0.5b fused step at its largest bucket
-with the pool shards ``repro.launch.serve`` allocates.  Nothing runs, so
+with the pool shards ``repro.launch.serve`` allocates, jitted as the
+engine jits it.  Nothing runs, so
 these say nothing about results or times; they catch what the chip's
 compiler refuses (tiling, fast-memory limits, a program that does not fit)
 before any chip time is spent.
@@ -12,6 +13,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every xdist worker imports
 every test file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,8 @@ from repro.kernels.paged_attention.kernel import (
     paged_attention_kernel, paged_prefill_attention_kernel)
 from repro.launch import serve
 from repro.models import transformer as T
-from repro.serving.engine import EngineConfig, _bucket, pool_shard_slots
+from repro.serving.engine import (EngineConfig, _bucket, jitted_steps,
+                                  pool_shard_slots)
 
 V5E_HBM_BYTES = 16e9
 
@@ -80,37 +84,64 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kernel, hkv, r, dh):
     assert text.count("tpu_custom_call") == 1
 
 
-def test_served_fused_step_fits_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def served_fused_step(one_chip):
     """The largest fused-step bucket of the served qwen1.5-0.5b engine
-    (every row, the full chunk, every page, every staging lane) compiles
-    with the Pallas kernel in it, and its arguments (weights + donated
-    pools) plus temporaries fit one chip's memory."""
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    cfg = get_config("qwen1.5-0.5b")
-    ecfg = EngineConfig(max_batch=serve.MAX_BATCH, max_seq=serve.MAX_SEQ)
-    slots, stage = pool_shard_slots(
-        cfg, ClusterSpec.build(serve.CLUSTER),
-        serve.PRIMARY_IDS + serve.POOL_IDS, ecfg)
-    anchor = serve.PRIMARY_IDS[0]
-    pshape = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
-    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), pshape)
-    pools = {d: _sds(one_chip, (cfg.n_layers,
-                                n + 1 + (stage if d == anchor else 0),
-                                ecfg.page_size, cfg.head_dim), cfg.kv_dtype)
-             for d, n in slots.items()}
-    B, C, G = ecfg.max_batch, ecfg.prefill_chunk, _bucket(stage)
-    P = -(-ecfg.max_seq // ecfg.page_size)
-    i32 = lambda *s: _sds(one_chip, s, jnp.int32)  # noqa: E731
-    lanes = [i32(G) for _ in range(6)]
-    rest = (i32(B, cfg.n_kv_heads, P), i32(B), i32(B),
-            i32(B, cfg.n_kv_heads, C), i32(B, C), i32(B, C), i32(B))
-    step = jax.jit(
-        lambda p, kp, vp, *a: T.sharded_fused_step(
-            cfg, p, kp, vp, anchor, slots[anchor], *a),
-        donate_argnums=(1, 2))
-    compiled = step.lower(params, pools, pools, *lanes, *rest).compile()
+    (every row, the full chunk, every page, every staging lane), as the
+    engine jits it (``engine.jitted_steps``), lowered and compiled with
+    the Pallas kernel in it: (lowered, compiled)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        cfg = get_config("qwen1.5-0.5b")
+        ecfg = EngineConfig(max_batch=serve.MAX_BATCH, max_seq=serve.MAX_SEQ)
+        slots, stage = pool_shard_slots(
+            cfg, ClusterSpec.build(serve.CLUSTER),
+            serve.PRIMARY_IDS + serve.POOL_IDS, ecfg)
+        anchor = serve.PRIMARY_IDS[0]
+        pshape = jax.eval_shape(
+            lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+        params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                              pshape)
+        pools = {d: _sds(one_chip, (cfg.n_layers,
+                                    n + 1 + (stage if d == anchor else 0),
+                                    ecfg.page_size, cfg.head_dim),
+                         cfg.kv_dtype)
+                 for d, n in slots.items()}
+        B, C, G = ecfg.max_batch, ecfg.prefill_chunk, _bucket(stage)
+        P = -(-ecfg.max_seq // ecfg.page_size)
+        i32 = lambda *s: _sds(one_chip, s, jnp.int32)  # noqa: E731
+        lanes = [i32(G) for _ in range(6)]
+        rest = (i32(B, cfg.n_kv_heads, P), i32(B), i32(B),
+                i32(B, cfg.n_kv_heads, C), i32(B, C), i32(B, C), i32(B))
+        step = jitted_steps(cfg, ecfg.max_seq, anchor, slots[anchor])[4]
+        lowered = step.lower(params, pools, pools, *lanes, *rest)
+        yield lowered, lowered.compile()
+
+
+def test_served_fused_step_fits_v5e(served_fused_step):
+    """The largest bucket compiles with exactly one Pallas kernel, and its
+    arguments (weights + donated pools) plus temporaries fit one chip's
+    memory."""
+    _, compiled = served_fused_step
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < V5E_HBM_BYTES, (mem.argument_size_in_bytes,
                                    mem.temp_size_in_bytes)
+
+
+def test_served_fused_step_names_and_scopes(served_fused_step):
+    """The fused step's module is ``jit_fused_step`` and its ops carry the
+    named scopes a profile is read by: the staging exchange in and out,
+    and the layer scan's attention and MLP."""
+    lowered, compiled = served_fused_step
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_fused_step,")
+    assert "jit__lambda" not in lowered.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("exchange_in/", "exchange_out/", "/layer/attn/",
+                  "/layer/mlp/", "/layer/qkv/", "/layer/kv_write/",
+                  "embed/", "lm_head/"):
+        assert any(scope in n for n in names), scope
+    # the kernel keeps its name, which the roofline metric matches
+    assert re.search(r"%paged_prefill_attention(\.\d+)? = ", text)
