@@ -178,19 +178,71 @@ def gqa_decode_paged(cfg, p, x, kpool, vpool, idx, block_tables, lengths,
     return logical(out, "batch", "seq", "embed"), kpool, vpool
 
 
+def write_chunk_pages(pool, idx, write_slots, write_offs, kv) -> jax.Array:
+    """``pool.at[idx, write_slots, write_offs[:, None, :]].set(kv)`` for a
+    chunk of contiguous token positions, written page by page.
+
+    Each row's chunk of C tokens spans at most ``(C + page - 2) // page +
+    1`` pages per KV group.  Those pages are gathered whole from layer
+    ``idx``, the chunk's tokens are merged in at their offsets (earlier
+    tokens of a partly filled page kept), and whole ``(page, dh)`` tiles
+    are scattered back.  A token merges only where its own write slot is
+    its page's slot, so padded tokens (sink-slotted) land in the sink
+    alone; local pages past a row's chunk repeat its last page, slot and
+    content alike, so repeated indices write equal values.
+
+    pool:        (L, slots, page, dh) stacked pool
+    write_slots: (B, Hkv, C) int32 slot of each chunk token's page
+    write_offs:  (B, C) int32 offset of each chunk token within its page;
+                 a row's valid tokens are a prefix at consecutive positions
+    kv:          (B, C, Hkv, dh) the chunk's K or V, cast to the pool's
+                 dtype (f8 where kv_cache_dtype sets it)
+    """
+    B, Hkv, C = write_slots.shape
+    page = pool.shape[2]
+    n_pages = (C + page - 2) // page + 1
+    off0 = write_offs[:, :1]                                      # (B, 1)
+    last = (off0 + C - 1) // page
+    j = jnp.minimum(jnp.arange(n_pages)[None, :], last)           # (B, P)
+    first = jnp.maximum(j * page - off0, 0)                       # (B, P)
+    page_slots = jnp.take_along_axis(
+        write_slots, jnp.broadcast_to(first[:, None], (B, Hkv, n_pages)),
+        axis=2)                                                   # (B,Hkv,P)
+    tok = (j[:, :, None] * page + jnp.arange(page)
+           - off0[:, :, None]).reshape(B, n_pages * page)         # (B, P*pg)
+    in_chunk = (tok >= 0) & (tok < C)
+    tok = jnp.clip(tok, 0, C - 1)
+    tok_slots = jnp.take_along_axis(
+        write_slots, jnp.broadcast_to(tok[:, None], (B, Hkv, tok.shape[1])),
+        axis=2).reshape(B, Hkv, n_pages, page)
+    merge = (in_chunk.reshape(B, 1, n_pages, page)
+             & (tok_slots == page_slots[..., None]))
+    new = jnp.take_along_axis(jnp.swapaxes(kv, 1, 2).astype(pool.dtype),
+                              tok[:, None, :, None], axis=2)
+    new = new.reshape(B, Hkv, n_pages, page, -1)
+    pages = jnp.where(merge[..., None], new, pool[idx, page_slots])
+    return pool.at[idx, page_slots].set(pages)
+
+
 def gqa_prefill_paged(cfg, p, x, kpool, vpool, idx, block_tables, lengths,
                       starts, write_slots, write_offs, positions
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Prefill one prompt CHUNK against the device-resident paged pool —
     the prefill symmetric of ``gqa_decode_paged``.
 
-    The chunk's K/V is scattered straight into this layer's pool slice
-    (B*C*Hkv*dh elements — no dense max_seq cache is ever materialized),
-    then the chunked-prefill Pallas kernel attends causally against the
+    The chunk's K/V is written straight into this layer's pool slice
+    (no dense max_seq cache is ever materialized), then the
+    chunked-prefill Pallas kernel attends causally against the
     pool through the block tables: each chunk token sees the request's
     stored prefix plus the in-chunk tokens at or before its own position.
     Padded rows carry lengths == 0 and padded tokens write to the sink
     slot, so garbage never reaches a real page or a used output.
+
+    The write goes in whole pages (``write_chunk_pages``), not token rows:
+    a scatter of C*Hkv rows of dh elements into the scan-carried stacked
+    pool made XLA relayout the whole pool out and back around it, twice
+    per pool and layer, at head_dim 64.  Page tiles scatter in place in
+    the pool's own layout at every head dim.
 
     x:            (B, C, d) chunk hidden states
     kpool/vpool:  (L, slots, page, dh) full stacked pools (scan carry)
@@ -208,11 +260,8 @@ def gqa_prefill_paged(cfg, p, x, kpool, vpool, idx, block_tables, lengths,
     with jax.named_scope("qkv"):
         q, k, v = _gqa_qkv(cfg, p, x, positions)
     with jax.named_scope("kv_write"):
-        cdt = kpool.dtype                    # may be f8 (kv_cache_dtype)
-        kpool = kpool.at[idx, write_slots, write_offs[:, None, :]].set(
-            jnp.swapaxes(k, 1, 2).astype(cdt))
-        vpool = vpool.at[idx, write_slots, write_offs[:, None, :]].set(
-            jnp.swapaxes(v, 1, 2).astype(cdt))
+        kpool = write_chunk_pages(kpool, idx, write_slots, write_offs, k)
+        vpool = write_chunk_pages(vpool, idx, write_slots, write_offs, v)
     with jax.named_scope("attn"):
         # group-major head fold (H = Hkv * r), matching attention_core
         qg = q.reshape(B, C, Hkv, H // Hkv, dh).transpose(0, 2, 1, 3, 4)

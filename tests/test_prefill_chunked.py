@@ -220,3 +220,52 @@ def test_chunked_prefill_no_dense_intermediate():
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
     eng.run_until_drained(300)
     assert len(eng.finished) == 3
+
+
+# rows of a mixed chunk batch as (start, n valid tokens); None pads a row
+WRITE_ROWS = [(0, 32),      # page-aligned, two whole pages
+              (5, 32),      # mid-page, three pages
+              (20, 12),     # mid-page, two pages, a prompt's last chunk
+              (31, 32),     # one token on the first page, three pages
+              (16, 17),     # two pages, one token on the second
+              (47, 1),      # a decode row, padded to the chunk
+              None]         # a padded row
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float8_e4m3fn"])
+@pytest.mark.parametrize("dh", [64, 96, 128])
+def test_chunk_page_write_matches_token_scatter(dh, dtype):
+    """``attn.write_chunk_pages`` leaves the stacked pool bit-identical,
+    outside the sink slot, to the token-row scatter it replaced, on
+    chunks that start mid-page and span one to three pages, a decode row,
+    and a padded row."""
+    from repro.models.attention import write_chunk_pages
+    L, page, C, Hkv, idx = 3, 16, 32, 2, 1
+    B = len(WRITE_ROWS)
+    n_slots = 64
+    sink = n_slots - 1
+    rng = np.random.default_rng(dh)
+    # each (row, group) owns its own pages, as the allocator hands them out
+    chains = rng.permutation(sink)[:B * Hkv * 4].reshape(B, Hkv, 4)
+    slots = np.full((B, Hkv, C), sink, np.int32)
+    offs = np.zeros((B, C), np.int32)
+    for b, row in enumerate(WRITE_ROWS):
+        if row is None:
+            continue
+        start, n = row
+        t = np.arange(start, start + n)
+        slots[b, :, :n] = chains[b][:, t // page]
+        offs[b, :n] = t % page
+    pool = jnp.asarray(rng.normal(size=(L, n_slots, page, dh)), dtype)
+    kv = jnp.asarray(rng.normal(size=(B, C, Hkv, dh)), jnp.bfloat16)
+    slots, offs = jnp.asarray(slots), jnp.asarray(offs)
+    want = pool.at[idx, slots, offs[:, None, :]].set(
+        jnp.swapaxes(kv, 1, 2).astype(pool.dtype))
+    got = jax.jit(write_chunk_pages)(pool, jnp.int32(idx), slots, offs, kv)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    bits = jnp.uint16 if pool.dtype.itemsize == 2 else jnp.uint8
+    as_bits = lambda a: np.asarray(  # noqa: E731
+        jax.lax.bitcast_convert_type(a, bits))[:, :sink]
+    np.testing.assert_array_equal(as_bits(got), as_bits(want))
+    # the chunk's tokens really landed outside the sink
+    assert not np.array_equal(as_bits(got), as_bits(pool))

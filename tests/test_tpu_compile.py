@@ -14,6 +14,7 @@ process may load the TPU library at a time, and every xdist worker imports
 every test file.
 """
 
+import math
 import re
 
 import jax
@@ -145,3 +146,64 @@ def test_served_fused_step_names_and_scopes(served_fused_step):
         assert any(scope in n for n in names), scope
     # the kernel keeps its name, which the roofline metric matches
     assert re.search(r"%paged_prefill_attention(\.\d+)? = ", text)
+
+
+def _pool_copies_in_loops(text, pool_shape):
+    """Copies, in any while body of compiled HLO ``text``, of an array
+    with as many elements as ``pool_shape``, whatever its shape or
+    layout (XLA flattens the pool to rows before relaying it out)."""
+    n = math.prod(pool_shape)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    found, inside = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            m = re.match(r"%?([\w.\-]+) ", line)
+            inside = bool(m) and m.group(1) in bodies
+            continue
+        m = re.search(r"= \w+\[([\d,]*)\]\S* copy(-start)?\(", line)
+        if inside and m and math.prod(
+                int(d) for d in m.group(1).split(",") if d) == n:
+            found.append(line.strip()[:120])
+    return found
+
+
+def test_served_fused_step_writes_pool_in_place(served_fused_step):
+    """The layer scan of the largest fused bucket writes the chunk's K/V
+    into the stacked anchor pool without relaying the whole pool out
+    (a token-row scatter at head_dim 64 made XLA transpose it twice per
+    pool and layer)."""
+    _, compiled = served_fused_step
+    text = compiled.as_text()
+    cfg = get_config("qwen1.5-0.5b")
+    pools = set(re.findall(r"bf16\[(%d,\d+,%d,%d)\]" % (
+        cfg.n_layers, EngineConfig().page_size, cfg.head_dim), text))
+    assert len(pools) >= 2 and re.search(r"body=", text)
+    for shape in pools:
+        dims = [int(d) for d in shape.split(",")]
+        assert _pool_copies_in_loops(text, dims) == [], shape
+
+
+@pytest.mark.parametrize("dh", [64, 96, 128])
+def test_chunk_page_write_in_scan_compiles_in_place(one_chip, dh):
+    """``write_chunk_pages`` on a stacked pool carried through a layer
+    ``lax.scan`` leaves no whole-pool copy in the loop, at each head
+    dim."""
+    from repro.models.attention import write_chunk_pages
+    L, slots, page, B, Hkv, C = 24, 16385, 16, 16, 16, 32
+    pool = _sds(one_chip, (L, slots, page, dh), jnp.bfloat16)
+
+    def write_all(kp, vp, ws, wo, kv):
+        def body(carry, idx):
+            kp, vp = carry
+            kp = write_chunk_pages(kp, idx, ws, wo, kv)
+            vp = write_chunk_pages(vp, idx, ws, wo, kv * 2)
+            return (kp, vp), None
+        return jax.lax.scan(body, (kp, vp), jnp.arange(L))[0]
+
+    args = (pool, pool, _sds(one_chip, (B, Hkv, C), jnp.int32),
+            _sds(one_chip, (B, C), jnp.int32),
+            _sds(one_chip, (B, C, Hkv, dh), jnp.bfloat16))
+    text = jax.jit(write_all, donate_argnums=(0, 1)).lower(
+        *args).compile().as_text()
+    assert re.search(r"body=", text)
+    assert _pool_copies_in_loops(text, (L, slots, page, dh)) == []
